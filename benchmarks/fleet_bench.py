@@ -37,6 +37,20 @@ import sys
 REPO_ROOT = pathlib.Path(__file__).resolve().parent.parent
 
 
+def refuse_on_accelerator():
+    """The fleet and wire benches time virtual CPU devices in child
+    processes pinned to `JAX_PLATFORMS=cpu`; on a chip host their JSON
+    would hold chip numbers beside CPU numbers with nothing to tell
+    them apart, so they refuse to run there."""
+    import jax
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise SystemExit(
+            f"{pathlib.Path(sys.argv[0]).name} is a CPU bench (virtual CPU "
+            f"devices); this host's default backend is {backend!r}. "
+            "Run chip_smoke.py on the chip instead.")
+
+
 def worker(args) -> None:
     """One device count, fresh backend (env set by the parent)."""
     import jax
@@ -112,6 +126,7 @@ def main() -> None:
         worker(args)
         return
 
+    refuse_on_accelerator()
     counts = [int(d) for d in args.devices.split(",")]
     results: dict = {}
     for d in counts:

@@ -174,6 +174,8 @@ def main():
         fleet_worker(args)
         return
 
+    from fleet_bench import refuse_on_accelerator   # benchmarks/ is on sys.path
+    refuse_on_accelerator()
     engine = {}
     for variant in WIRE_SPECS:
         engine[variant] = run_variant(variant, args)

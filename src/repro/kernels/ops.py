@@ -2,19 +2,18 @@
 
 Kernel dispatch is a three-way mode, resolved per call:
 
-    pallas — real Pallas lowering (TPU/GPU); auto-falls back to interp
-             when only CPU devices are visible, so requesting it never
-             crashes a CPU lane;
+    pallas — real Pallas lowering on a TPU.  Asking for it on a host
+             with no TPU raises: a chip run never silently measures the
+             interpreter;
     interp — `pallas_call(interpret=True)`: the SAME kernel bodies
              executed through the Pallas interpreter (what CPU/CI runs —
              the kernel code path stays exercised without an accelerator);
     ref    — the pure-jnp oracles in `kernels.ref` (debugging baseline).
 
 Precedence: an explicit `interpret=` argument > the `REPRO_KERNELS`
-env var (pallas|interp|ref) > the legacy `KERNEL_INTERPRET` flag
-(0 = pallas) > auto (pallas on TPU/GPU, interp on CPU).  GQA head
-expansion for flash attention happens here, not in the kernel (the
-kernel requires equal head counts).
+env var (pallas|interp|ref) > auto (pallas on TPU, interp elsewhere).
+GQA head expansion for flash attention happens here, not in the kernel
+(the kernel requires equal head counts).
 """
 from __future__ import annotations
 
@@ -35,17 +34,10 @@ from repro.kernels.wire_quant import (wire_dequant_pallas, wire_quant_pallas,
 
 KERNEL_MODES = ("pallas", "interp", "ref")
 
-# legacy flag kept for back-compat: KERNEL_INTERPRET=1 (the old default)
-# pins interpret mode, =0 asks for the real lowering.  `kernel_mode`
-# re-reads the env per call; this import-time snapshot is only kept for
-# back-compat with code that imported the old constant.
-INTERPRET = os.environ.get("KERNEL_INTERPRET", "1") == "1"
-
 
 def _has_accelerator() -> bool:
     try:
-        return any(d.platform in ("tpu", "gpu", "cuda", "rocm")
-                   for d in jax.devices())
+        return any(d.platform == "tpu" for d in jax.devices())
     except RuntimeError:
         return False
 
@@ -60,13 +52,10 @@ def kernel_mode() -> str:
             raise ValueError(
                 f"REPRO_KERNELS={mode!r}: must be one of {KERNEL_MODES}")
         if mode == "pallas" and not _has_accelerator():
-            return "interp"         # auto-fallback: CPU lanes still run
-        return mode                 # the kernel bodies via the interpreter
-    if "KERNEL_INTERPRET" in os.environ:
-        # read the VALUE per call too — a flag flipped after import
-        # must not dispatch against the import-time snapshot
-        return ("interp" if os.environ["KERNEL_INTERPRET"] == "1"
-                else "pallas")
+            raise RuntimeError(
+                "REPRO_KERNELS=pallas needs a TPU, and jax sees none; "
+                "use REPRO_KERNELS=interp (or leave it unset) on a CPU host")
+        return mode
     return "pallas" if _has_accelerator() else "interp"
 
 
@@ -124,6 +113,16 @@ def splitcat_linear_q8(qs, scales, w, b=None, *, out_dtype=jnp.float32,
                             interpret=(mode == "interp"))
 
 
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _wire_quant_jit(x, *, interpret):
+    return wire_quant_pallas(x, interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("dtype", "interpret"))
+def _wire_dequant_jit(q, scale, *, dtype, interpret):
+    return wire_dequant_pallas(q, scale, dtype, interpret=interpret)
+
+
 def wire_quantize(x, *, interpret: bool | None = None):
     """Fused per-row absmax quantize + int8 pack: x -> (q, row scales).
     Scalar (0-d) payloads — possible in the param trees the handoff and
@@ -135,7 +134,7 @@ def wire_quantize(x, *, interpret: bool | None = None):
     mode = _resolve(interpret)
     if mode == "ref":
         return ref.wire_quant_ref(x)
-    return wire_quant_pallas(x, interpret=(mode == "interp"))
+    return _wire_quant_jit(x, interpret=(mode == "interp"))
 
 
 def wire_dequantize(q, scale, dtype=jnp.float32, *,
@@ -146,7 +145,8 @@ def wire_dequantize(q, scale, dtype=jnp.float32, *,
     mode = _resolve(interpret)
     if mode == "ref":
         return ref.wire_dequant_ref(q, scale, dtype)
-    return wire_dequant_pallas(q, scale, dtype, interpret=(mode == "interp"))
+    return _wire_dequant_jit(q, scale, dtype=jnp.dtype(dtype),
+                             interpret=(mode == "interp"))
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",

@@ -20,22 +20,16 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.nn import module as nn
 
-
-def make_mesh_compat(shape, axes):
-    """jax.make_mesh across jax versions: `axis_types` (and
-    jax.sharding.AxisType itself) only exist on newer jax; older
-    releases default every axis to Auto anyway."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+# every mesh axis is `Auto`: arrays are placed by `NamedSharding` and GSPMD
+# resolves the rest (jax's default `Explicit` axes would make every gather
+# on a sharded client stack name its output sharding)
+AUTO = jax.sharding.AxisType.Auto
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AUTO,) * len(axes))
 
 
 # ---------------------------------------------------------------------------
@@ -72,8 +66,8 @@ def make_fleet_mesh(n_devices: int | None = None, *, model_parallel: int = 1,
             "export XLA_FLAGS="
             f"'--xla_force_host_platform_device_count={need}' before "
             "importing jax to split the host into virtual devices.")
-    return make_mesh_compat((n_devices, model_parallel),
-                            (client_axis, model_axis))
+    return jax.make_mesh((n_devices, model_parallel), (client_axis, model_axis),
+                         axis_types=(AUTO, AUTO))
 
 
 def batch_axes(mesh) -> tuple:

@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.models.registry import supports_split_serving
 from repro.serve import ServePlan, ServeSession, greedy_decode_scan
@@ -148,6 +149,7 @@ def main():
                     help="per-token Python-loop decode (bench baseline)")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced(vocab=256)

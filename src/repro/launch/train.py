@@ -37,6 +37,7 @@ from repro.api import FleetSpec, FullFns, Plan, lm_split_fns
 from repro.configs import get_config
 from repro.data import synthetic as syn
 from repro.engine import tree_index
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 
 
@@ -142,6 +143,7 @@ def main():
     if args.n_clients < 1:
         ap.error("--n-clients must be >= 1")
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced(vocab=256)

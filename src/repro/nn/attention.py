@@ -583,7 +583,7 @@ def gqa_decode_sharded(params, cfg: AttnConfig, x, cache, *, seq_axis):
     wspec = {"w": P(None, ax)}
     if has_bias:
         wspec = {"w": P(None, ax), "b": P(ax)}
-    y, new_k, new_v = _dist.shard_map(
+    y, new_k, new_v = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(dp, None, None), wspec, wspec, wspec,
                   {"w": P(ax, None)},

@@ -17,25 +17,17 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-# jax >= 0.5 exposes shard_map at top level; 0.4.x keeps it experimental
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map  # noqa: F401
-
 
 def shard_map_norep(f, **kw):
-    """`shard_map` with replication checking off — ONLY for the fleet
+    """`jax.shard_map` with replication checking off — ONLY for the fleet
     engines' round bodies: `pallas_call` (the physical wire's
-    quantize/pack kernels run inside them) has no replication rule on
-    this jax version, so check_rep=True would reject any fleet round
-    with a physical wire.  Everything else (attention/MoE model
-    parallelism) keeps the strict default — check_rep is exactly the
-    net that catches a forgotten psum on a replicated output."""
-    try:
-        return shard_map(f, check_rep=False, **kw)
-    except TypeError:       # newer jax: the kwarg was renamed/removed
-        return shard_map(f, **kw)
+    quantize/pack kernels run inside them) has no replication rule, so
+    `check_vma=True` would reject any fleet round with a physical wire.
+    Everything else (attention/MoE model parallelism) keeps the strict
+    default — `check_vma` is exactly the net that catches a forgotten
+    psum on a replicated output."""
+    return jax.shard_map(f, check_vma=False, **kw)
+
 
 _MESH = None
 

@@ -203,7 +203,7 @@ def moe_apply_ep(params, cfg: MoEConfig, x):
         out = jax.lax.psum(partial, ax)
         return out.reshape(Bl, S_, D_)
 
-    fn = _dist.shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(dp, None, None), P(None, None),
                   P(ax, None, None), P(ax, None, None), P(ax, None, None)),

@@ -115,10 +115,12 @@ class ServeSession:
         if not 0 < self.cut < n_layers:
             raise ValueError(f"cut {self.cut} outside (0, {n_layers})")
         self.stack = WireStack(parse_wire(plan.wire))
-        params = (self.model.init(key_or_params)
-                  if not isinstance(key_or_params, dict) else key_or_params)
-        self.client_params, self.server_params = self.model.split_params(
-            params, self.cut)
+        if isinstance(key_or_params, dict):
+            self.client_params, self.server_params = self.model.split_params(
+                key_or_params, self.cut)
+        else:
+            self.client_params, self.server_params = self._init_split(
+                key_or_params)
         self._fused = (self._fused_entry_weights()
                        if plan.fused_entry else None)
         if plan.fused_entry and self._fused is None:
@@ -127,6 +129,25 @@ class ServeSession:
                 "rmsnorm+attention block at the server entry")
         self._cc = self._sc = None
         self._build_jits()
+
+    def _init_split(self, key):
+        """Fresh params, built and split in ONE compiled program: the
+        full tree never exists beside its two halves, so the init peak
+        is the halves plus one leaf's temporaries (a 32-layer model's
+        eager init-then-slice peaks near twice its weights).  A tied
+        head leaves the program once, as the client's embedding, and
+        the server shares that buffer exactly as `split_params` does."""
+        model, cut = self.model, self.cut
+
+        def init_split(k):
+            client, server = model.split_params(model.init(k), cut)
+            server.pop("tied_head", None)
+            return client, server
+
+        client, server = jax.jit(init_split)(key)
+        if "head" not in server:
+            server["tied_head"] = client["embed"]
+        return client, server
 
     # ---- the fused packed-wire server entry --------------------------------
 

@@ -16,8 +16,8 @@ from repro.nn import moe as M
 
 @pytest.fixture(scope="module")
 def mesh11():
-    from repro.launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat((1, 1), ("data", "model"))
+    from repro.launch.mesh import AUTO
+    mesh = jax.make_mesh((1, 1), ("data", "model"), axis_types=(AUTO, AUTO))
     dist.set_mesh(mesh)
     return mesh
 

@@ -124,6 +124,28 @@ def test_packed_wire_bitwise_fake_and_3x_smaller(arch, red):
     assert all(w.physical for w in c_q8.wires)
 
 
+def test_session_init_matches_split_params():
+    """A session built from a key (init and split in one compiled
+    program) holds exactly the halves `split_params` cuts from the
+    compiled `model.init`, and its tied head shares the client's
+    embedding.  (A compiled init may round differently from the eager
+    one: the reference is compiled too.)"""
+    cfg = get_config("phi4_mini_3_8b").reduced(vocab=97)
+    model = build_model(cfg)
+    key = jax.random.PRNGKey(3)
+    sess = ServeSession(ServePlan(arch=cfg, max_batch=1, max_len=MAX_LEN),
+                        key)
+    want = model.split_params(jax.jit(model.init)(key), sess.cut)
+    got = (sess.client_params, sess.server_params)
+    assert (jax.tree_util.tree_structure(got)
+            == jax.tree_util.tree_structure(want))
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert a.dtype == b.dtype
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    assert sess.server_params["tied_head"] is sess.client_params["embed"]
+
+
 def test_decode_cost_counts_both_hops():
     cfg, model, params, prompt = _setup("phi4_mini_3_8b")
     sess = ServeSession(ServePlan(arch=cfg, max_batch=1, max_len=MAX_LEN,
@@ -178,15 +200,18 @@ def test_batcher_eos_frees_slot():
     cfg, model, params, prompt = _setup("phi4_mini_3_8b")
     sess = ServeSession(ServePlan(arch=cfg, max_batch=1, max_len=MAX_LEN,
                                   wire="quantize_int8:physical"), params)
-    solo = sess.generate(prompt[:1], GEN)
-    eos = int(np.asarray(solo)[0, 1])          # second generated token
+    solo = [int(t) for t in np.asarray(sess.generate(prompt[:1], GEN))[0]]
+    # EOS is checked on every sampled token, the prefill's first one
+    # included: pick the first token that has not appeared before it
+    k = next(i for i in range(1, GEN) if solo[i] not in solo[:i])
+    eos = solo[k]
     bat = Batcher(ServeSession(ServePlan(arch=cfg, max_batch=1,
                                          max_len=MAX_LEN,
                                          wire="quantize_int8:physical"),
                                params), eos_id=eos)
     bat.join(prompt[0], GEN)
     done = bat.run()
-    assert done[0].tokens[-1] == eos and len(done[0].tokens) == 2
+    assert done[0].tokens[-1] == eos and len(done[0].tokens) == k + 1
     assert bat.free_slots() == [0]             # slot immediately reusable
     bat.join(prompt[1], 2)
     assert len(bat.run()) == 1

@@ -13,7 +13,7 @@ fake<->physical training parity, and the bytes-accounting invariant.
 * accounting — metered bytes equal the ACTUAL nbytes of the packed
   payload pytree whenever a physical transform is active, and the
   `bytes_fn` claim cannot drift from it (`WireAccountingError`);
-* dispatch — `REPRO_KERNELS=pallas|interp|ref` with CPU auto-fallback.
+* dispatch — `REPRO_KERNELS=pallas|interp|ref`; pallas raises off-TPU.
 """
 import os
 
@@ -404,22 +404,25 @@ def test_stack_handoff_bytes_price_int8():
 # ---------------------------------------------------------------------------
 
 def test_repro_kernels_env_dispatch(monkeypatch):
-    monkeypatch.delenv("KERNEL_INTERPRET", raising=False)
     monkeypatch.setenv("REPRO_KERNELS", "ref")
     assert ops.kernel_mode() == "ref"
     monkeypatch.setenv("REPRO_KERNELS", "interp")
     assert ops.kernel_mode() == "interp"
-    # pallas auto-falls back to interp on this CPU-only container
+    # pallas is the real lowering: on a host with no TPU it raises
+    # instead of quietly running the interpreter
     monkeypatch.setenv("REPRO_KERNELS", "pallas")
-    assert ops.kernel_mode() == "interp" if not any(
-        d.platform in ("tpu", "gpu") for d in jax.devices()) else "pallas"
+    if any(d.platform == "tpu" for d in jax.devices()):
+        assert ops.kernel_mode() == "pallas"
+    else:
+        with pytest.raises(RuntimeError, match="needs a TPU"):
+            ops.kernel_mode()
     monkeypatch.setenv("REPRO_KERNELS", "bogus")
     with pytest.raises(ValueError, match="REPRO_KERNELS"):
         ops.kernel_mode()
-    # legacy flag still honored when REPRO_KERNELS is unset
+    # unset: the automatic default follows the backend
     monkeypatch.delenv("REPRO_KERNELS")
-    monkeypatch.setenv("KERNEL_INTERPRET", "1")
-    assert ops.kernel_mode() == "interp"
+    assert ops.kernel_mode() == ("pallas" if any(
+        d.platform == "tpu" for d in jax.devices()) else "interp")
 
 
 def test_all_kernel_modes_agree_on_wire_quant(monkeypatch):
@@ -431,6 +434,29 @@ def test_all_kernel_modes_agree_on_wire_quant(monkeypatch):
         outs[mode] = (np.asarray(q), np.asarray(s))
     np.testing.assert_array_equal(outs["interp"][0], outs["ref"][0])
     np.testing.assert_array_equal(outs["interp"][1], outs["ref"][1])
+
+
+def test_eager_wire_kernels_compile_once():
+    """Called outside any jit (the serving batcher densifies each step's
+    logits eagerly), the wire kernels reuse one compiled program per
+    shape instead of compiling on every call."""
+    x = jax.random.normal(KEY, (4, 40))
+    q, s = ops.wire_quantize(x)                       # warm both kernels
+    ops.wire_dequantize(q, s)
+    compiles = []
+
+    def listen(event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(duration)
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        for _ in range(3):
+            q, s = ops.wire_quantize(x)
+            ops.wire_dequantize(q, s)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    assert compiles == []
 
 
 def test_ref_mode_trains_a_physical_plan(monkeypatch):
