@@ -109,14 +109,21 @@ def vanilla_split_grads(model: SegModel, cut: int, params_c, params_s,
 
     Returns (loss, g_client, g_server).  The ONLY values linking the two
     sides are `act` (up) and `g_act` (down) — this is checked by tests.
+    Each part runs under its IR step's device scope
+    (`repro.engine.program.scope`).
     """
+    from repro.engine.program import (ClientBwd, ClientFwd, RecvGrad,
+                                      SendCut, ServerFwdBwd, scope)
     wires = wires if wires is not None else []
 
     def client_fwd(pc):
         return model.apply_range(pc, x, 0, cut)
 
-    act, client_vjp = jax.vjp(client_fwd, params_c)
-    act = record(wires, "cut_act", act, "up")
+    with scope(ClientFwd):
+        act, client_vjp = jax.vjp(client_fwd, params_c)
+    with scope(SendCut):
+        act = record(wires, "cut_act", act, "up")
+        act_in = as_dense(act)
 
     def server_loss(ps, a):
         logits = model.apply_range(ps, a, cut, model.n_segments,
@@ -125,11 +132,14 @@ def vanilla_split_grads(model: SegModel, cut: int, params_c, params_s,
                                                            model.n_segments)
         return loss_fn(logits, labels)
 
-    (loss, ), vjp_s = jax.vjp(lambda ps, a: (server_loss(ps, a),),
-                              params_s, as_dense(act))
-    g_server, g_act = vjp_s((jnp.ones(()),))
-    g_act = record(wires, "cut_grad", g_act, "down")
-    (g_client,) = client_vjp(as_dense(g_act))
+    with scope(ServerFwdBwd):
+        (loss, ), vjp_s = jax.vjp(lambda ps, a: (server_loss(ps, a),),
+                                  params_s, act_in)
+        g_server, g_act = vjp_s((jnp.ones(()),))
+    with scope(RecvGrad):
+        g_act = as_dense(record(wires, "cut_grad", g_act, "down"))
+    with scope(ClientBwd):
+        (g_client,) = client_vjp(g_act)
     return loss, g_client, g_server, wires
 
 

@@ -41,6 +41,7 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.core.accounting import (Meter, TurnCost, bytes_of_tree,
                                    flops_of_fn, probe_wire_records)
@@ -85,6 +86,8 @@ class RoundEngine:
                 f"{self.topology.kind} topology exposes no staged turn "
                 "(pipeline_fwd/rest/bwd) — pipelined schedule unavailable")
         self.meter = Meter(self.n_clients)
+        self.rounds = 0                 # rounds run (the span's `round`)
+        self.host_reads = 0             # device values read on the host
         self._client_param_bytes = 0
         self._turn_costs: dict = {}     # batch-shape key -> TurnCost
         # p2p handoff middleware: transforms flagged handoff=True squeeze
@@ -136,11 +139,22 @@ class RoundEngine:
     def run_round(self, state, batches):
         """batches: dict of (N, ...) arrays (see stack_batches), except
         vertical where labels are shared: {"x": (N,B,...), "labels": (B,)}.
-        Returns (state, per-turn losses (N,)).  Also meters the round."""
-        first = bool(state["last_trained"] < 0)
-        self.turn_cost(state, batches)          # probe once per shape
-        state, losses = self._round_jit(state, batches)
-        self._account_round(state, batches, first_round=first)
+        Returns (state, per-turn losses (N,)).  Also meters the round.
+
+        Host spans `repro.engine.*` (recorded only while a profiler
+        session is active) name the round's parts; `host_reads` counts
+        the device values it reads back."""
+        with TraceAnnotation("repro.engine.run_round", round=self.rounds):
+            with TraceAnnotation("repro.engine.host_read"):
+                first = bool(state["last_trained"] < 0)
+            self.host_reads += 1
+            with TraceAnnotation("repro.engine.turn_cost"):
+                self.turn_cost(state, batches)      # probe once per shape
+            with TraceAnnotation("repro.engine.launch"):
+                state, losses = self._round_jit(state, batches)
+            with TraceAnnotation("repro.engine.account"):
+                self._account_round(state, batches, first_round=first)
+        self.rounds += 1
         return state, losses
 
     def _round(self, state, batches):

@@ -38,6 +38,22 @@ Executors are interchangeable interpreters of the same program:
 The executors interpret the program through the staged callables the
 lowering attached (`Topology.pipeline_fwd/rest/bwd`, `turn_grads`,
 `round_grads`) — they own scheduling only, never mode dispatch.
+
+Device scopes.  Each part of a turn runs under a `jax.named_scope`
+named by its step class (`scope(ClientFwd)` -> "ClientFwd"; the turn
+functions in `core.split` and `topology.vanilla_fns`, the handoff in
+the executors), and the optimizer step under `OPTIMIZER_SCOPE`: both
+optimizers' updates, `apply_updates`, and the write-back of the turn's
+client weights and optimizer state into the stacks.  The scope lands in
+each HLO instruction's `op_name` metadata, e.g.
+`jit(_round)/while/body/closed_call/ClientFwd/jvp()/conv_general_dilated`.
+A backward op of `jax.vjp` carries the scope its vjp was CALLED in
+(`.../ClientBwd/transpose(jvp())/...`); under `jax.grad` of a function
+that opens the scope itself, it carries the forward's inside the
+transform (`transpose(jvp(ClientFwd))`).  The rule that maps an
+`op_name` to one step: the last path component that names a scope
+wins, and `ClientFwd` under `transpose(` reads as `ClientBwd`.  A
+profile of the round then splits device time by step.
 """
 from __future__ import annotations
 
@@ -49,6 +65,9 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.optim import apply_updates
+
+# the device scope of the optimizer step (see the module docstring)
+OPTIMIZER_SCOPE = "optimizer"
 
 # ---------------------------------------------------------------------------
 # stacked-pytree helpers (canonical home; repro.engine re-exports)
@@ -199,6 +218,12 @@ class WeightHandoff(Step):
 WIRE_STEPS = (SendCut, RecvGrad)
 
 
+def scope(step: type):
+    """The device scope of one IR step class: ops traced under it carry
+    the class name in their `op_name` (module docstring)."""
+    return jax.named_scope(step.__name__)
+
+
 # ---------------------------------------------------------------------------
 # the program
 # ---------------------------------------------------------------------------
@@ -316,20 +341,23 @@ def run_serial(program: StepProgram, ctx: ExecContext, state, batches):
             # pull the last trained client's weights (p2p handoff);
             # with wire middleware the payload crosses the same
             # quantized wire the cut activations do
-            prev = tree_index(clients, jnp.maximum(last, 0))
-            if ctx.wire_handoff:
-                prev = ctx.wire_stack.handoff_recv(prev)
-            take = (last >= 0) & (last != ci)
-            pc = jax.tree_util.tree_map(
-                lambda own, pv: jnp.where(take, pv, own), pc, prev)
+            with scope(WeightHandoff):
+                prev = tree_index(clients, jnp.maximum(last, 0))
+                if ctx.wire_handoff:
+                    prev = ctx.wire_stack.handoff_recv(prev)
+                take = (last >= 0) & (last != ci)
+                pc = jax.tree_util.tree_map(
+                    lambda own, pv: jnp.where(take, pv, own), pc, prev)
         loss, g_c, g_s = topo.turn_grads(pc, server, batch, ctx.loss_fn)
-        ups_c, oc = ctx.optimizer_client.update(
-            g_c, tree_index(opt_c, ci), pc)
-        pc = apply_updates(pc, ups_c)
-        ups_s, opt_s = ctx.optimizer_server.update(g_s, opt_s, server)
-        server = apply_updates(server, ups_s)
-        return ((tree_update(clients, ci, pc),
-                 tree_update(opt_c, ci, oc), server, opt_s, ci), loss)
+        with jax.named_scope(OPTIMIZER_SCOPE):
+            ups_c, oc = ctx.optimizer_client.update(
+                g_c, tree_index(opt_c, ci), pc)
+            pc = apply_updates(pc, ups_c)
+            ups_s, opt_s = ctx.optimizer_server.update(g_s, opt_s, server)
+            server = apply_updates(server, ups_s)
+            clients = tree_update(clients, ci, pc)
+            opt_c = tree_update(opt_c, ci, oc)
+        return (clients, opt_c, server, opt_s, ci), loss
 
     carry = (state["clients"], state["opt_c"], state["server"],
              state["opt_s"], state["last_trained"])
@@ -346,15 +374,8 @@ def run_parallel(program: StepProgram, ctx: ExecContext, state, batches):
     losses, g_c, g_s = jax.vmap(
         lambda pc, b: topo.turn_grads(pc, state["server"], b, ctx.loss_fn),
         in_axes=(0, 0))(state["clients"], batches)
-    ups_c, opt_c = jax.vmap(ctx.optimizer_client.update)(
-        g_c, state["opt_c"], state["clients"])
-    clients = apply_updates(state["clients"], ups_c)
     g_s_mean = jax.tree_util.tree_map(lambda g: g.mean(0), g_s)
-    ups_s, opt_s = ctx.optimizer_server.update(
-        g_s_mean, state["opt_s"], state["server"])
-    server = apply_updates(state["server"], ups_s)
-    return {"clients": clients, "server": server, "opt_c": opt_c,
-            "opt_s": opt_s, "last_trained": state["last_trained"]}, losses
+    return _branch_step(ctx, state, losses, g_c, g_s_mean)
 
 
 def run_branch(program: StepProgram, ctx: ExecContext, state, batches):
@@ -380,12 +401,15 @@ def run_branch_pipelined(program: StepProgram, ctx: ExecContext, state,
 
 
 def _branch_step(ctx, state, losses, g_c, g_s):
-    ups_c, opt_c = jax.vmap(ctx.optimizer_client.update)(
-        g_c, state["opt_c"], state["clients"])
-    clients = apply_updates(state["clients"], ups_c)
-    ups_s, opt_s = ctx.optimizer_server.update(
-        g_s, state["opt_s"], state["server"])
-    server = apply_updates(state["server"], ups_s)
+    """Every client steps on its own stacked gradient, the server once
+    on `g_s`."""
+    with jax.named_scope(OPTIMIZER_SCOPE):
+        ups_c, opt_c = jax.vmap(ctx.optimizer_client.update)(
+            g_c, state["opt_c"], state["clients"])
+        clients = apply_updates(state["clients"], ups_c)
+        ups_s, opt_s = ctx.optimizer_server.update(
+            g_s, state["opt_s"], state["server"])
+        server = apply_updates(state["server"], ups_s)
     return {"clients": clients, "server": server, "opt_c": opt_c,
             "opt_s": opt_s, "last_trained": state["last_trained"]}, losses
 
@@ -414,26 +438,29 @@ def run_pipelined(program: StepProgram, ctx: ExecContext, state, batches):
         batch = {k: v[ci] for k, v in batches.items()}
         pc = tree_at(clients, ci)
         if sync:
-            if prev_pc is None:
-                # round boundary: adopt the globally last-trained
-                # client's weights (masked out before the first turn)
-                prev = tree_index(clients, jnp.maximum(last, 0))
-                if ctx.wire_handoff:
-                    prev = ctx.wire_stack.handoff_recv(prev)
-                take = (last >= 0) & (last != ci)
-                pc = jax.tree_util.tree_map(
-                    lambda own, pv: jnp.where(take, pv, own), pc, prev)
-            else:
-                pc = (ctx.wire_stack.handoff_recv(prev_pc)
-                      if ctx.wire_handoff else prev_pc)
+            with scope(WeightHandoff):
+                if prev_pc is None:
+                    # round boundary: adopt the globally last-trained
+                    # client's weights (masked out before the first turn)
+                    prev = tree_index(clients, jnp.maximum(last, 0))
+                    if ctx.wire_handoff:
+                        prev = ctx.wire_stack.handoff_recv(prev)
+                    take = (last >= 0) & (last != ci)
+                    pc = jax.tree_util.tree_map(
+                        lambda own, pv: jnp.where(take, pv, own), pc, prev)
+                else:
+                    pc = (ctx.wire_stack.handoff_recv(prev_pc)
+                          if ctx.wire_handoff else prev_pc)
         loss, g_c, g_s = _pipelined_turn(topo, ctx.loss_fn, pc, server,
                                          batch, m, program.split_batch)
-        ups_c, oc = ctx.optimizer_client.update(g_c, tree_at(opt_c, ci), pc)
-        pc = apply_updates(pc, ups_c)
-        ups_s, opt_s = ctx.optimizer_server.update(g_s, opt_s, server)
-        server = apply_updates(server, ups_s)
-        clients = tree_set(clients, ci, pc)
-        opt_c = tree_set(opt_c, ci, oc)
+        with jax.named_scope(OPTIMIZER_SCOPE):
+            ups_c, oc = ctx.optimizer_client.update(g_c, tree_at(opt_c, ci),
+                                                    pc)
+            pc = apply_updates(pc, ups_c)
+            ups_s, opt_s = ctx.optimizer_server.update(g_s, opt_s, server)
+            server = apply_updates(server, ups_s)
+            clients = tree_set(clients, ci, pc)
+            opt_c = tree_set(opt_c, ci, oc)
         prev_pc = pc
         losses.append(loss)
     return {"clients": clients, "server": server, "opt_c": opt_c,
@@ -498,6 +525,7 @@ EXECUTORS = {
 __all__ = [
     "Step", "ClientFwd", "SendCut", "ServerFwdBwd", "RecvGrad", "ClientBwd",
     "Aggregate", "WeightHandoff", "StepProgram", "ExecContext", "EXECUTORS",
+    "OPTIMIZER_SCOPE", "scope",
     "run_serial", "run_parallel", "run_branch", "run_branch_pipelined",
     "run_pipelined", "split_turn_batch", "split_branch_batch",
     "stack_trees", "unstack_tree", "tree_index", "tree_update", "tree_at",

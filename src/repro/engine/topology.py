@@ -212,20 +212,25 @@ def vanilla_fns(init_full: Callable, split: Callable, client_apply: Callable,
                 server_apply: Callable) -> Topology:
     """Vanilla topology over opaque client/server apply functions (the
     `models.lm.LM` split hooks) instead of a SegModel.  Same wire protocol
-    as `core.split.vanilla_split_grads`: only the cut activation (up) and
-    its gradient (down) cross."""
+    and device scopes as `core.split.vanilla_split_grads`: only the cut
+    activation (up) and its gradient (down) cross."""
     def init(key):
         return split(init_full(key))
 
     def turn_grads_wires(pc, ps, batch, loss_fn, wires):
-        act, vjp_c = jax.vjp(lambda p: client_apply(p, batch), pc)
-        act = sp.record(wires, "cut_act", act, "up")
-        (loss,), vjp_s = jax.vjp(
-            lambda p, a: (loss_fn(server_apply(p, a), batch["labels"]),),
-            ps, sp.as_dense(act))
-        g_s, g_act = vjp_s((jnp.ones(()),))
-        g_act = sp.record(wires, "cut_grad", g_act, "down")
-        (g_c,) = vjp_c(sp.as_dense(g_act))
+        with ir.scope(ir.ClientFwd):
+            act, vjp_c = jax.vjp(lambda p: client_apply(p, batch), pc)
+        with ir.scope(ir.SendCut):
+            act = sp.as_dense(sp.record(wires, "cut_act", act, "up"))
+        with ir.scope(ir.ServerFwdBwd):
+            (loss,), vjp_s = jax.vjp(
+                lambda p, a: (loss_fn(server_apply(p, a), batch["labels"]),),
+                ps, act)
+            g_s, g_act = vjp_s((jnp.ones(()),))
+        with ir.scope(ir.RecvGrad):
+            g_act = sp.as_dense(sp.record(wires, "cut_grad", g_act, "down"))
+        with ir.scope(ir.ClientBwd):
+            (g_c,) = vjp_c(g_act)
         return loss, g_c, g_s
 
     def evaluate(pc, ps, batch):

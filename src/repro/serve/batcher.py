@@ -23,6 +23,11 @@ parity suite checks batched == solo slot-for-slot, token-exact).
 Wire bytes are metered analytically per ACTIVE tenant from the
 `eval_shape` TurnCost probes — vacant-slot padding is free on a real
 wire and is not billed.
+
+Host spans `repro.batcher.*` name the parts of `join` and `step` in any
+profiler capture (they record nothing while no profiler session is
+active); `host_reads` counts the device values read on the host, one
+per token.
 """
 from __future__ import annotations
 
@@ -30,6 +35,7 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.core.wire_compress import PackedInt8, as_dense, stack_packed
 from repro.models.lm import per_slot_pos
@@ -45,6 +51,7 @@ class Tenant:
     cache: object                 # B=1 client-side caches
     cur: object                   # (1, 1) current token
     done: bool = False
+    serial: int = 0               # order of admission (the spans' `tenant`)
 
 
 class Batcher:
@@ -61,6 +68,8 @@ class Batcher:
         self.bytes_up = 0
         self.bytes_down = 0
         self.tokens_generated = 0
+        self.host_reads = 0                   # device values read on the host
+        self.joined = 0                       # tenants admitted so far
 
         model, cut, plan = session.model, session.cut, session.plan
         _, sc = model.init_cache_split(self.max_batch, plan.max_len, cut)
@@ -108,25 +117,33 @@ class Batcher:
         free = self.free_slots()
         if not free:
             raise RuntimeError("batch full — no free slot")
-        b = free[0]
-        prompt = jnp.asarray(prompt)
-        if prompt.ndim == 1:
-            prompt = prompt[None]
-        sess = self.session
-        batch = {"tokens": prompt}
-        if extra:
-            batch.update(extra)
-        tok0, cc, sc1 = sess._jit_prefill(sess.client_params,
-                                          sess.server_params, batch)
-        self._sc = self._jit_scatter(self._sc, sc1, b)
-        pc = sess.prefill_cost(1, prompt.shape[1], extra)
-        self.bytes_up += pc.bytes_up
-        self.bytes_down += pc.bytes_down
-        self.tokens_generated += 1
-        t = Tenant(slot=b, max_new=max_new, tokens=[int(tok0[0, 0])],
-                   cache=cc, cur=tok0)
-        self.tenants[b] = t
-        self._maybe_finish(t)
+        b, serial = free[0], self.joined
+        self.joined += 1
+        with TraceAnnotation("repro.batcher.join", tenant=serial, slot=b):
+            prompt = jnp.asarray(prompt)
+            if prompt.ndim == 1:
+                prompt = prompt[None]
+            sess = self.session
+            batch = {"tokens": prompt}
+            if extra:
+                batch.update(extra)
+            with TraceAnnotation("repro.batcher.prefill"):
+                tok0, cc, sc1 = sess._jit_prefill(sess.client_params,
+                                                  sess.server_params, batch)
+            with TraceAnnotation("repro.batcher.scatter"):
+                self._sc = self._jit_scatter(self._sc, sc1, b)
+            with TraceAnnotation("repro.batcher.price"):
+                pc = sess.prefill_cost(1, prompt.shape[1], extra)
+            self.bytes_up += pc.bytes_up
+            self.bytes_down += pc.bytes_down
+            self.tokens_generated += 1
+            with TraceAnnotation("repro.batcher.first_token"):
+                first = int(tok0[0, 0])
+            self.host_reads += 1
+            t = Tenant(slot=b, max_new=max_new, tokens=[first], cache=cc,
+                       cur=tok0, serial=serial)
+            self.tenants[b] = t
+            self._maybe_finish(t)
         return b
 
     # ---- the batched step --------------------------------------------------
@@ -134,8 +151,10 @@ class Batcher:
     def _part(self, b):
         t = self.tenants.get(b)
         if t is not None and not t.done:
-            act, t.cache = self._jit_client(self.session.client_params,
-                                            t.cur, t.cache)
+            with TraceAnnotation("repro.batcher.client", tenant=t.serial,
+                                 slot=b):
+                act, t.cache = self._jit_client(self.session.client_params,
+                                                t.cur, t.cache)
             return act
         if self._pad_part is None:
             d = self.session.cfg.d_model
@@ -149,22 +168,27 @@ class Batcher:
         live = [b for b, t in self.tenants.items() if not t.done]
         if not live:
             return {}
-        parts = [self._part(b) for b in range(self.max_batch)]
-        payload = stack_packed(parts, axis=0)
-        logits, self._sc = self._jit_server(self.session.server_params,
-                                            payload, self._sc)
-        toks = jnp.argmax(as_dense(logits)[:, -1], axis=-1)
-        out = {}
-        for b in live:
-            t = self.tenants[b]
-            tok = int(toks[b])
-            t.tokens.append(tok)
-            t.cur = toks[b][None, None].astype(jnp.int32)
-            out[b] = tok
-            self.bytes_up += self._decode_up
-            self.bytes_down += self._decode_down
-            self.tokens_generated += 1
-            self._maybe_finish(t)
+        with TraceAnnotation("repro.batcher.step"):
+            parts = [self._part(b) for b in range(self.max_batch)]
+            with TraceAnnotation("repro.batcher.stack"):
+                payload = stack_packed(parts, axis=0)
+            with TraceAnnotation("repro.batcher.server"):
+                logits, self._sc = self._jit_server(
+                    self.session.server_params, payload, self._sc)
+            with TraceAnnotation("repro.batcher.tokens"):
+                toks = jnp.argmax(as_dense(logits)[:, -1], axis=-1)
+                out = {}
+                for b in live:
+                    t = self.tenants[b]
+                    tok = int(toks[b])
+                    self.host_reads += 1
+                    t.tokens.append(tok)
+                    t.cur = toks[b][None, None].astype(jnp.int32)
+                    out[b] = tok
+                    self.bytes_up += self._decode_up
+                    self.bytes_down += self._decode_down
+                    self.tokens_generated += 1
+                    self._maybe_finish(t)
         return out
 
     def _maybe_finish(self, t: Tenant):
