@@ -323,7 +323,7 @@ def phase_serve(seed, *, arch=PHI4, parity_layers=4,
 
     def step():
         t0 = time.perf_counter()
-        bat.step()                  # reads every sampled token on the host
+        bat.step()                  # one host read of the step's tokens
         step_s.append(time.perf_counter() - t0)
 
     join(0)

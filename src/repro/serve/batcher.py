@@ -13,8 +13,10 @@ Per step the batcher:
   2. concatenates the payloads along the batch axis
      (`wire_compress.stack_packed` — bitwise the per-tenant payloads,
      because quantization is per last-axis row);
-  3. runs ONE batched server step over the stacked payload;
-  4. hands each tenant its own logits row for client-side argmax.
+  3. runs ONE batched server step over the stacked payload, which also
+     takes every slot's greedy token from the down-wire logits;
+  4. reads the step's tokens on the host in one transfer and hands each
+     tenant its own token, already on the device as its next input.
 
 Vacant slots ride along as zero payloads: every op in the server trunk
 is batch-row-independent, so garbage rows cannot perturb live rows (the
@@ -26,8 +28,8 @@ wire and is not billed.
 
 Host spans `repro.batcher.*` name the parts of `join` and `step` in any
 profiler capture (they record nothing while no profiler session is
-active); `host_reads` counts the device values read on the host, one
-per token.
+active); `host_reads` counts the device values read on the host: one
+per join and one per step with a live tenant (`steps`).
 """
 from __future__ import annotations
 
@@ -35,6 +37,7 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.profiler import TraceAnnotation
 
 from repro.core.wire_compress import PackedInt8, as_dense, stack_packed
@@ -70,6 +73,7 @@ class Batcher:
         self.tokens_generated = 0
         self.host_reads = 0                   # device values read on the host
         self.joined = 0                       # tenants admitted so far
+        self.steps = 0                        # steps with a live tenant
 
         model, cut, plan = session.model, session.cut, session.plan
         _, sc = model.init_cache_split(self.max_batch, plan.max_len, cut)
@@ -92,7 +96,13 @@ class Batcher:
             else:
                 logits, sc = model.decode_step_server(sp, as_dense(payload),
                                                       cut, sc)
-            return stack.apply(logits, "logits", "down"), sc
+            logits = stack.apply(logits, "logits", "down")
+            toks = jnp.argmax(as_dense(logits)[:, -1], axis=-1
+                              ).astype(jnp.int32)
+            # every slot's token again as its own (1, 1) output: the
+            # tenant's next `cur`, with no dispatch on the host
+            curs = tuple(toks[b][None, None] for b in range(toks.shape[0]))
+            return toks, curs, sc
 
         def scatter(full, one, b):
             """Write a tenant's B=1 server cache into stacked slot `b`.
@@ -173,17 +183,18 @@ class Batcher:
             with TraceAnnotation("repro.batcher.stack"):
                 payload = stack_packed(parts, axis=0)
             with TraceAnnotation("repro.batcher.server"):
-                logits, self._sc = self._jit_server(
+                toks, curs, self._sc = self._jit_server(
                     self.session.server_params, payload, self._sc)
             with TraceAnnotation("repro.batcher.tokens"):
-                toks = jnp.argmax(as_dense(logits)[:, -1], axis=-1)
+                toks = np.asarray(toks)
+                self.host_reads += 1
+                self.steps += 1
                 out = {}
                 for b in live:
                     t = self.tenants[b]
                     tok = int(toks[b])
-                    self.host_reads += 1
                     t.tokens.append(tok)
-                    t.cur = toks[b][None, None].astype(jnp.int32)
+                    t.cur = curs[b]
                     out[b] = tok
                     self.bytes_up += self._decode_up
                     self.bytes_down += self._decode_down

@@ -10,7 +10,9 @@
   decode payload is >= 3x smaller than the fp32 split wire's, derived
   from the actual packed leaf dtypes via `TurnCost`;
 * the multi-tenant `Batcher` reproduces every tenant's solo token
-  stream slot-for-slot, including a tenant joining mid-flight;
+  stream slot-for-slot, including a tenant joining mid-flight; a step
+  reads its tokens on the host once, however many tenants are live,
+  and a tenant decodes next from whatever its `cur` holds;
 * the fused packed-entry path (`splitcat_linear_packed` consuming the
   payload inside the server's first block) generates the same tokens.
 """
@@ -194,6 +196,52 @@ def test_batcher_midstream_join():
     want = np.asarray(solo)
     assert got[s0] == [int(x) for x in want[0]]
     assert got[s1] == [int(x) for x in want[1]]
+
+
+@pytest.fixture(scope="module")
+def batcher3():
+    cfg, _, params, prompt = _setup("phi4_mini_3_8b")
+    bat = Batcher(ServeSession(ServePlan(arch=cfg, max_batch=3,
+                                         max_len=MAX_LEN,
+                                         wire="quantize_int8:physical"),
+                               params))
+    return cfg, bat, prompt
+
+
+@pytest.mark.parametrize("live", [1, 3], ids=["one_live", "batch_full"])
+def test_batcher_step_reads_tokens_once(batcher3, live):
+    """One host read per step whatever the occupancy; each tenant's next
+    client step takes its `cur`, also when overwritten between steps."""
+    cfg, bat, prompt = batcher3
+    for i in range(live):
+        bat.join(prompt[i % B], GEN)
+    assert bat.host_reads - bat.joined == bat.steps
+    taken = []                          # each client step's token, slot order
+    client = bat._jit_client
+
+    def spy(cp, tok, cc):
+        taken.append(int(np.asarray(tok)[0, 0]))
+        return client(cp, tok, cc)
+    bat._jit_client = spy
+    try:
+        for n in range(3):
+            reads, steps = bat.host_reads, bat.steps
+            tenants = sorted(bat.tenants.values(), key=lambda t: t.slot)
+            first = tenants[0]
+            if n == 1:                          # the planted fault's move
+                first.tokens[-1] = (first.tokens[-1] + 1) % cfg.vocab
+                first.cur = jnp.asarray([[first.tokens[-1]]], jnp.int32)
+            want = [t.tokens[-1] for t in tenants]
+            del taken[:]
+            out = bat.step()
+            assert taken == want
+            assert sorted(out) == [t.slot for t in tenants]
+            assert bat.host_reads - reads == bat.steps - steps == 1
+            assert bat.host_reads - bat.joined == bat.steps
+    finally:
+        bat._jit_client = client
+    bat.run()
+    assert bat.host_reads - bat.joined == bat.steps
 
 
 def test_batcher_eos_frees_slot():

@@ -4,7 +4,7 @@
   and of reduced round-robin rounds holds every `repro.*` host span,
   nested as documented, with its `tenant` / `slot` / `round` arguments;
 * `Batcher.host_reads` and `RoundEngine.host_reads` are exact: one per
-  join, one per live tenant per step, one per round;
+  join, one per step with a live tenant, one per round;
 * the compiled round carries an `op_name` under every IR step scope
   and under `optimizer`, for both vanilla turn functions;
 * none of it compiles anything new on a second call.
@@ -194,14 +194,16 @@ def test_host_reads_are_exact(engine_run, batcher_run):
     assert sess.engine.host_reads - reads == sess.engine.rounds - rounds == 3
 
     bat, prompt, _ = batcher_run
-    reads, tokens = bat.host_reads, bat.tokens_generated
+    reads, tokens, steps = bat.host_reads, bat.tokens_generated, bat.steps
     bat.join(prompt, 8)
     bat.join(prompt, 8)
     assert bat.host_reads - reads == 2                  # one per join
     for _ in range(3):
         bat.step()
-    assert bat.host_reads - reads == 2 + 2 * 3          # one per live tenant
-    assert bat.tokens_generated - tokens == bat.host_reads - reads
+    assert bat.host_reads - reads == 2 + 3              # one per step
+    assert bat.steps - steps == 3
+    assert bat.host_reads - bat.joined == bat.steps
+    assert bat.tokens_generated - tokens == 2 + 2 * 3   # one per live tenant
     bat.run()
     bat.finished.clear()
 
