@@ -8,6 +8,8 @@ from typing import Any
 
 import jax.numpy as jnp
 
+from repro.nn.attention import Yarn
+
 
 @dataclasses.dataclass(frozen=True)
 class ArchConfig:
@@ -23,13 +25,18 @@ class ArchConfig:
     qkv_bias: bool = False
     rope_fraction: float = 1.0
     rope_theta: float = 10000.0
+    yarn: Yarn | None = None       # YaRN rope scaling (MLA archs)
     norm: str = "rmsnorm"
     mlp: str = "swiglu"            # swiglu | gelu
     tie_embeddings: bool = False
     # --- MoE ---
-    n_experts: int = 0
+    n_experts: int = 0             # router width: every routed expert
     top_k: int = 0
     n_shared: int = 0
+    experts_held: int = 0          # routed experts this chip holds (0: all)
+    expert_offset: int = 0         # id of the first held expert
+    norm_topk_prob: bool = True    # renormalize the top-k router weights
+    routed_scaling_factor: float = 1.0
     dense_d_ff: int = 0            # ffn width of non-MoE layers
     first_dense: int = 0           # first k layers use a dense ffn
     # --- attention kind ---
@@ -79,7 +86,13 @@ class ArchConfig:
             dtype=jnp.float32,
         )
         if self.n_experts:
-            small.update(n_experts=min(self.n_experts, 4),
+            n_exp = min(self.n_experts, 4)
+            if self.experts_held and self.experts_held < self.n_experts:
+                # a share smaller than the router, as the full size holds
+                held = max(1, n_exp // 2)
+                small.update(experts_held=held, expert_offset=min(
+                    self.expert_offset, n_exp - held))
+            small.update(n_experts=n_exp,
                          top_k=min(self.top_k, 2),
                          n_shared=min(self.n_shared, 1),
                          dense_d_ff=min(self.dense_d_ff, 256)
@@ -90,6 +103,10 @@ class ArchConfig:
                          kv_lora_rank=min(self.kv_lora_rank, 32),
                          qk_nope_head_dim=32, qk_rope_head_dim=16,
                          v_head_dim=32, head_dim=32)
+            if self.yarn is not None:
+                # an original context short enough for CPU tests to cross
+                small.update(yarn=dataclasses.replace(
+                    self.yarn, original_max=min(self.yarn.original_max, 16)))
         if self.family == "ssm":
             small.update(ssm_state=min(self.ssm_state, 32),
                          ssm_head_dim=32, ssm_chunk=8)
@@ -125,7 +142,7 @@ INPUT_SHAPES = {
 ARCH_IDS = [
     "qwen1_5_32b", "mamba2_130m", "mistral_large_123b", "deepseek_v2_236b",
     "recurrentgemma_2b", "internvl2_2b", "qwen3_moe_30b_a3b", "chatglm3_6b",
-    "phi4_mini_3_8b", "whisper_base",
+    "phi4_mini_3_8b", "whisper_base", "deepseek_v2_lite",
 ]
 
 

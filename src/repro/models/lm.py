@@ -55,7 +55,9 @@ def _attn_cfg(cfg: ArchConfig, *, window=None) -> A.AttnConfig:
             qk_nope_head_dim=cfg.qk_nope_head_dim,
             qk_rope_head_dim=cfg.qk_rope_head_dim,
             v_head_dim=cfg.v_head_dim, rope_theta=cfg.rope_theta,
-            window=window, dtype=cfg.dtype)
+            yarn=cfg.yarn, window=window, dtype=cfg.dtype)
+    if cfg.yarn is not None:
+        raise ValueError(f"{cfg.name}: YaRN is implemented for MLA only")
     return A.AttnConfig(
         d_model=cfg.d_model, n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
         head_dim=cfg.resolved_head_dim, qkv_bias=cfg.qkv_bias,
@@ -71,7 +73,11 @@ def _block_spec(cfg: ArchConfig, kind: str, *, window=None,
         if moe_layer:
             moe = M.MoEConfig(d_model=cfg.d_model, d_ff=cfg.d_ff,
                               n_experts=cfg.n_experts, top_k=cfg.top_k,
-                              n_shared=cfg.n_shared, dtype=cfg.dtype)
+                              n_shared=cfg.n_shared, held=cfg.experts_held,
+                              expert_offset=cfg.expert_offset,
+                              norm_topk_prob=cfg.norm_topk_prob,
+                              routed_scaling_factor=cfg.routed_scaling_factor,
+                              dtype=cfg.dtype)
             return T.BlockSpec(mixer=kind, mlp="moe", attn=attn, moe=moe,
                                **common)
         d_ff = cfg.dense_d_ff or cfg.d_ff

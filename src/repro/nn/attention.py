@@ -45,6 +45,7 @@ class AttnConfig:
     # KV cache storage: "native" | "int8" (per-(slot,head) symmetric
     # quantization — halves the decode memory floor vs bf16)
     kv_cache_dtype: str = "native"
+    yarn: "Yarn | None" = None        # YaRN rope scaling (MLA only)
     dtype: Any = jnp.float32
 
 
@@ -56,8 +57,50 @@ def rope_freqs(dim: int, theta: float) -> jnp.ndarray:
     return 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
 
 
+@dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN rope scaling as DeepSeek-V2 configures it (`rope_scaling`
+    with `type` "yarn"): frequencies interpolated by `factor` above the
+    band that rotates fewer than `beta_slow` times over
+    `original_max` positions, kept below the band that rotates more than
+    `beta_fast` times, a linear ramp between; cos/sin scaled by
+    m(mscale) / m(mscale_all_dim) and MLA's softmax by m(mscale_all_dim)^2,
+    with m(a) = 0.1 a ln(factor) + 1."""
+    factor: float
+    original_max: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    def m(self, a: float) -> float:
+        return 0.1 * a * math.log(self.factor) + 1.0 if self.factor > 1 \
+            else 1.0
+
+    def band(self, dim: int, theta: float) -> tuple[int, int]:
+        """(low, high): the ramp's ends among the dim/2 frequencies."""
+        def corr(rotations):
+            return (dim * math.log(self.original_max
+                                   / (rotations * 2 * math.pi))
+                    / (2 * math.log(theta)))
+        return (max(math.floor(corr(self.beta_fast)), 0),
+                min(math.ceil(corr(self.beta_slow)), dim - 1))
+
+
+def yarn_freqs(dim: int, theta: float, yarn: Yarn) -> jnp.ndarray:
+    """(dim/2,) inverse frequencies: f_i (1 - ramp_i) + f_i / s ramp_i."""
+    low, high = yarn.band(dim, theta)
+    if high == low:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    f = rope_freqs(dim, theta)
+    return f * (1.0 - ramp) + (f / yarn.factor) * ramp
+
+
 def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, *, theta: float,
-               fraction: float = 1.0) -> jnp.ndarray:
+               fraction: float = 1.0, yarn: Yarn | None = None
+               ) -> jnp.ndarray:
     """x: (B, S, H, hd); positions: (B, S) or (S,)."""
     hd = x.shape[-1]
     rot = int(hd * fraction)
@@ -65,12 +108,16 @@ def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, *, theta: float,
     if rot == 0:
         return x
     x_rot, x_pass = x[..., :rot], x[..., rot:]
-    freqs = rope_freqs(rot, theta)                        # (rot/2,)
+    freqs = (rope_freqs(rot, theta) if yarn is None
+             else yarn_freqs(rot, theta, yarn))           # (rot/2,)
     if positions.ndim == 1:
         positions = positions[None, :]
     ang = positions[..., None].astype(jnp.float32) * freqs  # (B, S, rot/2)
     cos = jnp.cos(ang)[:, :, None, :]
     sin = jnp.sin(ang)[:, :, None, :]
+    if yarn is not None:
+        c = yarn.m(yarn.mscale) / yarn.m(yarn.mscale_all_dim)
+        cos, sin = cos * c, sin * c
     x1, x2 = jnp.split(x_rot.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return jnp.concatenate([out.astype(x.dtype), x_pass], axis=-1)
@@ -112,6 +159,31 @@ def grouped_attention(q, k, v, mask, *, scale: float) -> jnp.ndarray:
     w = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bkgst,btkd->bskgd", w, v.astype(jnp.float32))
     return out.reshape(B, S, H, v.shape[-1]).astype(q.dtype)
+
+
+def causal_attention(q, k, v, *, scale: float, window: int | None = None,
+                     block: int | None = None) -> jnp.ndarray:
+    """`grouped_attention` of a full sequence under its causal mask.
+    With `block`, queries go in blocks of `block` rows (one `lax.map`
+    step each) so that the (S, S) score matrix never exists whole: each
+    row's softmax is the same, over the same keys and mask.  A last
+    partial block is padded with queries whose rows are dropped."""
+    B, S = q.shape[:2]
+    if block is None or S <= block:
+        return grouped_attention(q, k, v, causal_mask(S, S, window=window),
+                                 scale=scale)
+    n = -(-S // block)
+    qb = jnp.pad(q, ((0, 0), (0, n * block - S), (0, 0), (0, 0)))
+    qb = jnp.swapaxes(qb.reshape(B, n, block, *q.shape[2:]), 0, 1)
+
+    def one(args):
+        i, qi = args
+        mask = causal_mask(block, S, window=window, q_offset=i * block)
+        return grouped_attention(qi, k, v, mask, scale=scale)
+
+    out = jax.lax.map(one, (jnp.arange(n), qb))       # (n, B, block, H, dv)
+    out = jnp.swapaxes(out, 0, 1).reshape(B, n * block, *out.shape[3:])
+    return out[:, :S]
 
 
 # ---------------------------------------------------------------------------
@@ -348,28 +420,60 @@ def _mla_q(params, cfg: AttnConfig, x):
     return q[..., :dn], q[..., dn:]                      # nope, rope parts
 
 
-def mla_apply(params, cfg: AttnConfig, x, *, positions=None, mask=None):
-    """Prefill/train: decompress k,v and run standard MHA."""
+def mla_scale(cfg: AttnConfig) -> float:
+    """MLA's softmax scale: 1/sqrt(dn + dr), times m(mscale_all_dim)^2
+    under YaRN."""
+    scale = 1.0 / math.sqrt(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
+    if cfg.yarn is not None:
+        scale *= cfg.yarn.m(cfg.yarn.mscale_all_dim) ** 2
+    return scale
+
+
+def _mla_rope(cfg: AttnConfig, x, positions):
+    return apply_rope(x, positions, theta=cfg.rope_theta, yarn=cfg.yarn)
+
+
+# query rows per block of a long MLA prefill: a 6,144-token prompt's
+# scores over 16 heads would be 2.4 GB in f32 whole, 0.4 GB a block
+MLA_PREFILL_BLOCK = 1024
+
+
+def _mla_full(params, cfg: AttnConfig, x, positions, *, block=None,
+              mask=None):
+    """Full-sequence MLA with decompressed keys and values: the output
+    (B, S, D) and the compressed rows (post-norm c_kv, rope'd k_pe).
+    Causal unless `mask` is given."""
     B, S, _ = x.shape
     H = cfg.n_heads
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     r = cfg.kv_lora_rank
-    if positions is None:
-        positions = jnp.arange(S)
     q_nope, q_pe = _mla_q(params, cfg, x)
-    q_pe = apply_rope(q_pe, positions, theta=cfg.rope_theta)
+    q_pe = _mla_rope(cfg, q_pe, positions)
     kv = L.dense_apply(params["wkv_a"], x)               # (B,S,r+dr)
     c_kv, k_pe = kv[..., :r], kv[..., r:]
     c_kv = L.rmsnorm_apply(params["kv_norm"], c_kv)
-    k_pe = apply_rope(k_pe[:, :, None, :], positions, theta=cfg.rope_theta)
+    k_pe = _mla_rope(cfg, k_pe[:, :, None, :], positions)[:, :, 0, :]
     k_nope = L.dense_apply(params["wk_b"], c_kv).reshape(B, S, H, dn)
     v = L.dense_apply(params["wv_b"], c_kv).reshape(B, S, H, dv)
     q = jnp.concatenate([q_nope, q_pe], axis=-1)
-    k = jnp.concatenate([k_nope, jnp.broadcast_to(k_pe, (B, S, H, dr))], axis=-1)
-    if mask is None:
-        mask = causal_mask(S, S, window=cfg.window)
-    out = grouped_attention(q, k, v, mask, scale=1.0 / math.sqrt(dn + dr))
-    return L.dense_apply(params["wo"], out.reshape(B, S, -1))
+    k = jnp.concatenate(
+        [k_nope, jnp.broadcast_to(k_pe[:, :, None, :], (B, S, H, dr))],
+        axis=-1)
+    with jax.named_scope("MLAAttend"):
+        if mask is None:
+            out = causal_attention(q, k, v, scale=mla_scale(cfg),
+                                   window=cfg.window, block=block)
+        else:
+            out = grouped_attention(q, k, v, mask, scale=mla_scale(cfg))
+    y = L.dense_apply(params["wo"], out.reshape(B, S, -1))
+    return y, c_kv, k_pe
+
+
+def mla_apply(params, cfg: AttnConfig, x, *, positions=None, mask=None):
+    """Prefill/train: decompress k,v and run standard MHA."""
+    if positions is None:
+        positions = jnp.arange(x.shape[1])
+    return _mla_full(params, cfg, x, positions, mask=mask)[0]
 
 
 def mla_init_cache(cfg: AttnConfig, batch: int, max_len: int):
@@ -389,7 +493,7 @@ def mla_decode(params, cfg: AttnConfig, x, cache):
     As in `gqa_decode`, `cache["pos"]` may be scalar or per-row (B,)."""
     B = x.shape[0]
     H = cfg.n_heads
-    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    dn, dv = cfg.qk_nope_head_dim, cfg.v_head_dim
     r = cfg.kv_lora_rank
     pos = cache["pos"]
     per_row = jnp.ndim(pos) == 1
@@ -397,64 +501,48 @@ def mla_decode(params, cfg: AttnConfig, x, cache):
                                                       dtype=jnp.int32)
 
     q_nope, q_pe = _mla_q(params, cfg, x)                # (B,1,H,dn),(B,1,H,dr)
-    q_pe = apply_rope(q_pe, positions, theta=cfg.rope_theta)
+    q_pe = _mla_rope(cfg, q_pe, positions)
 
     kv = L.dense_apply(params["wkv_a"], x)
     c_new, kpe_new = kv[..., :r], kv[..., r:]
     c_new = L.rmsnorm_apply(params["kv_norm"], c_new)
-    kpe_new = apply_rope(kpe_new[:, :, None, :], positions,
-                         theta=cfg.rope_theta)[:, :, 0, :]
+    kpe_new = _mla_rope(cfg, kpe_new[:, :, None, :], positions)[:, :, 0, :]
 
     cache_len = cache["c_kv"].shape[1]
     slot = jnp.mod(pos, cache_len)
     c_kv = _ring_put(cache["c_kv"], c_new, slot, per_row)
     k_pe = _ring_put(cache["k_pe"], kpe_new, slot, per_row)
 
-    # absorb wk_b into q: q_eff[b,h,r'] = sum_dn q_nope * wk_b[r', h, dn]
-    wk_b = params["wk_b"]["w"].reshape(r, H, dn)
-    q_eff = jnp.einsum("bshd,rhd->bshr", q_nope.astype(jnp.float32),
-                       wk_b.astype(jnp.float32))         # (B,1,H,r)
-    scores = jnp.einsum("bshr,btr->bhst", q_eff,
-                        c_kv.astype(jnp.float32))
-    scores = scores + jnp.einsum("bshd,btd->bhst", q_pe.astype(jnp.float32),
-                                 k_pe.astype(jnp.float32))
-    scores = scores / math.sqrt(dn + dr)
-    valid = _valid_mask(pos, cache_len, B, per_row)      # (B,1,T)
-    scores = jnp.where(valid[:, None, :, :], scores, NEG_INF)
-    w = jax.nn.softmax(scores, axis=-1)                  # (B,H,1,T)
-    ctx = jnp.einsum("bhst,btr->bshr", w, c_kv.astype(jnp.float32))  # (B,1,H,r)
-    wv_b = params["wv_b"]["w"].reshape(r, H, dv)
-    out = jnp.einsum("bshr,rhd->bshd", ctx, wv_b.astype(jnp.float32))
+    with jax.named_scope("MLAAttend"):
+        # absorb wk_b into q: q_eff[b,h,r'] = sum_dn q_nope * wk_b[r', h, dn]
+        wk_b = params["wk_b"]["w"].reshape(r, H, dn)
+        q_eff = jnp.einsum("bshd,rhd->bshr", q_nope.astype(jnp.float32),
+                           wk_b.astype(jnp.float32))     # (B,1,H,r)
+        scores = jnp.einsum("bshr,btr->bhst", q_eff,
+                            c_kv.astype(jnp.float32))
+        scores = scores + jnp.einsum("bshd,btd->bhst",
+                                     q_pe.astype(jnp.float32),
+                                     k_pe.astype(jnp.float32))
+        scores = scores * mla_scale(cfg)
+        valid = _valid_mask(pos, cache_len, B, per_row)  # (B,1,T)
+        scores = jnp.where(valid[:, None, :, :], scores, NEG_INF)
+        w = jax.nn.softmax(scores, axis=-1)              # (B,H,1,T)
+        ctx = jnp.einsum("bhst,btr->bshr", w,
+                         c_kv.astype(jnp.float32))       # (B,1,H,r)
+        wv_b = params["wv_b"]["w"].reshape(r, H, dv)
+        out = jnp.einsum("bshr,rhd->bshd", ctx, wv_b.astype(jnp.float32))
     y = L.dense_apply(params["wo"], out.reshape(B, 1, H * dv).astype(x.dtype))
     return y, {"c_kv": c_kv, "k_pe": k_pe, "pos": pos + 1}
 
 
 def mla_prefill(params, cfg: AttnConfig, x, cache):
-    """Full-sequence MLA forward (same math as `mla_apply`) that also
-    scatters the COMPRESSED rows — post-norm c_kv and rope'd k_pe, exactly
-    what `mla_decode` stores — into a fresh cache, leaving pos = S."""
-    B, S, _ = x.shape
-    H = cfg.n_heads
-    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-    r = cfg.kv_lora_rank
-    positions = jnp.arange(S)
-    q_nope, q_pe = _mla_q(params, cfg, x)
-    q_pe = apply_rope(q_pe, positions, theta=cfg.rope_theta)
-    kv = L.dense_apply(params["wkv_a"], x)               # (B,S,r+dr)
-    c_kv, k_pe = kv[..., :r], kv[..., r:]
-    c_kv = L.rmsnorm_apply(params["kv_norm"], c_kv)
-    k_pe = apply_rope(k_pe[:, :, None, :], positions,
-                      theta=cfg.rope_theta)[:, :, 0, :]  # (B,S,dr)
-    k_nope = L.dense_apply(params["wk_b"], c_kv).reshape(B, S, H, dn)
-    v = L.dense_apply(params["wv_b"], c_kv).reshape(B, S, H, dv)
-    q = jnp.concatenate([q_nope, q_pe], axis=-1)
-    k = jnp.concatenate(
-        [k_nope, jnp.broadcast_to(k_pe[:, :, None, :], (B, S, H, dr))],
-        axis=-1)
-    mask = causal_mask(S, S, window=cfg.window)
-    out = grouped_attention(q, k, v, mask, scale=1.0 / math.sqrt(dn + dr))
-    y = L.dense_apply(params["wo"], out.reshape(B, S, -1))
-
+    """Full-sequence MLA forward (same math as `mla_apply`, queries in
+    blocks of `MLA_PREFILL_BLOCK`) that also scatters the COMPRESSED
+    rows — post-norm c_kv and rope'd k_pe, exactly what `mla_decode`
+    stores — into a fresh cache, leaving pos = S."""
+    S = x.shape[1]
+    y, c_kv, k_pe = _mla_full(params, cfg, x, jnp.arange(S),
+                              block=MLA_PREFILL_BLOCK)
     cache_len = cache["c_kv"].shape[1]
     keep = min(S, cache_len)
     slots = jnp.arange(S - keep, S) % cache_len

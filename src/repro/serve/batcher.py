@@ -178,7 +178,7 @@ class Batcher:
         live = [b for b, t in self.tenants.items() if not t.done]
         if not live:
             return {}
-        with TraceAnnotation("repro.batcher.step"):
+        with TraceAnnotation("repro.batcher.step", live=len(live)):
             parts = [self._part(b) for b in range(self.max_batch)]
             with TraceAnnotation("repro.batcher.stack"):
                 payload = stack_packed(parts, axis=0)

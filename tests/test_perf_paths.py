@@ -25,7 +25,7 @@ def mesh11():
 def test_moe_ep_matches_gspmd_path(mesh11):
     key = jax.random.PRNGKey(0)
     cfg = M.MoEConfig(d_model=16, d_ff=32, n_experts=4, top_k=2,
-                      n_shared=1, capacity_factor=8.0)
+                      n_shared=1)
     params = M.moe_init(key, cfg)
     x = jax.random.normal(key, (2, 8, 16))
     ref = M.moe_apply(params, cfg, x)
@@ -38,7 +38,7 @@ def test_moe_ep_matches_gspmd_path(mesh11):
 def test_moe_ep_grads_flow(mesh11):
     key = jax.random.PRNGKey(1)
     cfg = M.MoEConfig(d_model=8, d_ff=16, n_experts=4, top_k=2,
-                      capacity_factor=8.0, ep_axis="model")
+                      ep_axis="model")
     params = M.moe_init(key, cfg)
     x = jax.random.normal(key, (1, 4, 8))
     with mesh11:

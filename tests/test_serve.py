@@ -49,7 +49,11 @@ def _mono_generate(model, params, prompt, max_new):
 
 ARCHS = [("phi4_mini_3_8b", {}),                     # GQA attention
          ("mamba2_130m", {}),                        # SSM ring-free cache
-         ("recurrentgemma_2b", {"n_layers": 6})]     # rglru+window hybrid
+         ("recurrentgemma_2b", {"n_layers": 6}),     # rglru+window hybrid
+         ("deepseek_v2_lite",                        # MLA latent cache, YaRN,
+          {"experts_held": 2, "expert_offset": 2})]  # MoE: a chip's share
+# the stacked-cache paths: attention, SSM and the latent cache with MoE
+BATCHED = ARCHS[:2] + ARCHS[3:]
 
 
 @pytest.mark.parametrize("arch,red", ARCHS, ids=[a for a, _ in ARCHS])
@@ -107,7 +111,7 @@ def test_split_fp32_matches_monolithic(arch, red):
     assert np.array_equal(np.asarray(mono), np.asarray(split))
 
 
-@pytest.mark.parametrize("arch,red", ARCHS[:2], ids=[a for a, _ in ARCHS[:2]])
+@pytest.mark.parametrize("arch,red", BATCHED, ids=[a for a, _ in BATCHED])
 def test_packed_wire_bitwise_fake_and_3x_smaller(arch, red):
     cfg, model, params, prompt = _setup(arch, **red)
     mk = lambda wire: ServeSession(
@@ -160,7 +164,7 @@ def test_decode_cost_counts_both_hops():
     assert cost.bytes_up == cfg.d_model + 4
 
 
-@pytest.mark.parametrize("arch,red", ARCHS[:2], ids=[a for a, _ in ARCHS[:2]])
+@pytest.mark.parametrize("arch,red", BATCHED, ids=[a for a, _ in BATCHED])
 def test_batcher_matches_solo_slot_for_slot(arch, red):
     cfg, model, params, prompt = _setup(arch, **red)
     solo = ServeSession(ServePlan(arch=cfg, max_batch=B, max_len=MAX_LEN,

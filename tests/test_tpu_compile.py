@@ -86,3 +86,27 @@ def test_splitcat_linear_q8_compiles_for_v5e(one_chip):
                                                   out_dtype=jnp.bfloat16),
         q, s, w)
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("tokens", [8, 6144], ids=["decode", "prefill"])
+def test_held_experts_compile_for_v5e(one_chip, tokens):
+    """DeepSeek-V2-Lite's MoE on one chip of eight: 8 held experts of
+    width 1,408 at d_model 2,048, top-6 of 64, over a decode step of 8
+    slots and a 6,144-token prefill.  The grouped product compiles to the
+    chip's ragged dot (its group metadata kernel), not a dense product
+    per expert."""
+    from repro.nn import moe as M
+    cfg = M.MoEConfig(d_model=2048, d_ff=1408, n_experts=64, top_k=6,
+                      held=8, norm_topk_prob=False, dtype=jnp.bfloat16)
+    sd = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    experts = {"gate": sd((8, 2048, 1408), jnp.bfloat16),
+               "up": sd((8, 2048, 1408), jnp.bfloat16),
+               "down": sd((8, 1408, 2048), jnp.bfloat16)}
+
+    def layer(router, experts, x):
+        _, w, eid = M.route({"router": {"w": router}}, cfg, x)
+        return M.held_experts(experts, cfg, x, w, eid, 0)
+
+    text = _compiled_text(layer, sd((2048, 64), jnp.float32), experts,
+                          sd((tokens, 2048), jnp.bfloat16))
+    assert "ragged-dot" in text

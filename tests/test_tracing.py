@@ -6,7 +6,9 @@
 * `Batcher.host_reads` and `RoundEngine.host_reads` are exact: one per
   join, one per step with a live tenant, one per round;
 * the compiled round carries an `op_name` under every IR step scope
-  and under `optimizer`, for both vanilla turn functions;
+  and under `optimizer`, for both vanilla turn functions; the compiled
+  server step and prefill of an MLA + MoE model carry the MoE and MLA
+  scopes;
 * none of it compiles anything new on a second call.
 """
 import pathlib
@@ -22,6 +24,7 @@ from repro.api import Plan, SplitFns
 from repro.api.wire import parse_wire
 from repro.configs import get_config
 from repro.core import split as sp
+from repro.core.wire_compress import stack_packed
 from repro.engine import program as ir
 from repro.serve import Batcher, ServePlan, ServeSession
 
@@ -169,7 +172,7 @@ def test_batcher_spans_nest_with_tenant_and_slot(batcher_run, tmp_path):
     assert [(j[3]["tenant"], j[3]["slot"]) for j in joins] == [
         (serial, slots[0]), (serial + 1, slots[1])]
     steps = named(spans, "repro.batcher.step")
-    assert len(steps) == 3
+    assert [s[3]["live"] for s in steps] == [2, 2, 2]
     assert all(parent(spans, s) is None for s in joins + steps)
     for names, outer, count in ((JOIN_PARTS, "repro.batcher.join", 2),
                                 (STEP_PARTS, "repro.batcher.step", 3)):
@@ -226,6 +229,41 @@ def test_compiled_round_carries_every_step_scope(make):
         assert any(re.search(rf"\b{scope}\b", p) for p in paths), scope
     # backward ops carry the scope their vjp was called in
     assert any("ClientBwd/transpose(" in p for p in paths)
+
+
+def _compiled_text(jitted, *args) -> str:
+    """Compiled text of a jitted function, outside the persistent cache
+    (its key leaves metadata out: an entry compiled without scopes would
+    come back without them)."""
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        return jitted.lower(*args).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+def test_compiled_serving_carries_moe_and_mla_scopes():
+    """The batched server step (decode through the latent cache, MoE
+    layers) carries every MoE scope and `MLAAttend`; the prefill's
+    attention is under `MLAAttend` too."""
+    cfg = get_config("deepseek_v2_lite").reduced(vocab=97, experts_held=2)
+    bat = Batcher(ServeSession(ServePlan(arch=cfg, max_batch=2, max_len=24,
+                                         wire="quantize_int8:physical"),
+                               jax.random.PRNGKey(0)))
+    sess = bat.session
+    payload = stack_packed([bat._part(b) for b in range(2)], axis=0)
+    step = _compiled_text(bat._jit_server, sess.server_params, payload,
+                          bat._sc)
+    prefill = _compiled_text(sess._jit_prefill, sess.client_params,
+                             sess.server_params,
+                             {"tokens": jnp.zeros((1, 6), jnp.int32)})
+    for text, scopes in ((step, ("MoERoute", "MoEExperts", "MoECombine",
+                                 "MLAAttend")),
+                         (prefill, ("MoEExperts", "MLAAttend"))):
+        paths = re.findall(r'op_name="([^"]*)"', text)
+        for scope in scopes:
+            assert any(re.search(rf"\b{scope}\b", p) for p in paths), scope
 
 
 def test_spans_compile_nothing_new(engine_run, batcher_run, tmp_path):
